@@ -48,8 +48,11 @@ def run_forced_device_child(code: str, device_count: int,
     The child environment is derived, not replaced: any existing
     ``XLA_FLAGS`` tokens are kept (only a previous device-count force is
     replaced with ours), and the repo's ``src`` is PREPENDED to whatever
-    ``PYTHONPATH`` the user already exported."""
+    ``PYTHONPATH`` the user already exported.  The child is a virtual-device
+    CPU run by design, so ``JAX_PLATFORMS=cpu`` keeps it off any accelerator
+    the parent may hold."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     flags = [t for t in env.get("XLA_FLAGS", "").split()
              if not t.startswith("--xla_force_host_platform_device_count")]
     flags.append(f"--xla_force_host_platform_device_count={int(device_count)}")

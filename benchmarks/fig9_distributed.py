@@ -239,7 +239,7 @@ drained = (fleet.pending_rows() == 0 and not rep2.excluded_shards
            and any(a.shard == LOST for a in rep2.actions))
 
 print(json.dumps({
-    "devices": 8, "n_views": V, "rows_per_view": R, "act_depth": D,
+    "platform": jax.devices()[0].platform, "devices": 8, "n_views": V, "rows_per_view": R, "act_depth": D,
     "curve": curve, "combine_s": combine_s, "scaling_at_8": scaling_at_8,
     "parity": {"mesh_vs_host_bit_equal": parity_mesh,
                "host_vs_flat_bit_equal": parity_flat,
@@ -264,6 +264,7 @@ def run(quick: bool = False) -> List[Row]:
     parity = out["parity"]
     payload = {
         "quick": bool(quick),
+        "platform": out["platform"],
         "devices": out["devices"],
         "n_views": out["n_views"],
         "rows_per_view": out["rows_per_view"],
@@ -293,7 +294,8 @@ def run(quick: bool = False) -> List[Row]:
         json.dump(payload, f, indent=2)
 
     cp8 = out["curve"][-1]["critical_path_s"]
-    der = (f"scaling_at_8={out['scaling_at_8']:.2f}x "
+    der = (f"platform={out['platform']} "
+           f"scaling_at_8={out['scaling_at_8']:.2f}x "
            f"parity={payload['guards']['parity_ok']} "
            f"availability={out['availability']:.2f} "
            f"drain={out['drained_after_revive']} "

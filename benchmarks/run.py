@@ -41,6 +41,9 @@ def main() -> None:
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
 
+    from repro.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     print("name,us_per_call,derived")
     failures = 0
     for mod_name in MODULES:

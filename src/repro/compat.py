@@ -1,8 +1,8 @@
-"""jax version-compatibility shims.
+"""Mesh helpers shared by the sharded paths.
 
-The codebase targets the current jax API (``jax.shard_map``, meshes with
-``axis_types``); older 0.4.x containers predate both names.  Import from
-here instead of feature-testing at every call site.
+One place fixes the arguments the installed jax wants for a shard_map and
+a mesh (no replication check; Auto axis types), so call sites never spell
+them out.
 """
 
 from __future__ import annotations
@@ -11,20 +11,14 @@ import jax
 
 
 def shard_map(fn, mesh, in_specs, out_specs):
-    """jax.shard_map with the vma/rep check off, on whichever jax is here."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    from jax.experimental.shard_map import shard_map as _sm  # jax <= 0.4.x
-
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
+    """jax.shard_map with the vma check off."""
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    )
 
 
 def make_mesh(shape, axes):
-    """jax.make_mesh with Auto axis types where the kwarg exists."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)  # older jax: Auto is the only behaviour
+    """jax.make_mesh with Auto axis types."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )

@@ -55,6 +55,20 @@ def splitmix32(x: jnp.ndarray) -> jnp.ndarray:
     return x
 
 
+def u01(h: jnp.ndarray) -> jnp.ndarray:
+    """uint32 hash → float32 in [0,1) (~2^-24 resolution).
+
+    Mosaic, the TPU kernel compiler, has no uint32 → float32 cast, so each
+    16-bit half converts exactly through int32 and one float32 add rounds
+    the exact value to nearest-even: the same bits as XLA's convert.  The
+    Pallas kernels and ``hash_u01`` share this function, so every η
+    decision is bit-identical across paths (Prop. 2)."""
+    h = h.astype(jnp.uint32)
+    hi = (h >> 16).astype(jnp.int32).astype(jnp.float32)
+    lo = (h & jnp.uint32(0xFFFF)).astype(jnp.int32).astype(jnp.float32)
+    return (hi * jnp.float32(65536.0) + lo) * jnp.float32(1.0 / 4294967296.0)
+
+
 def hash_columns(cols: Sequence[jnp.ndarray], seed: int = 0) -> jnp.ndarray:
     """Mix (composite) key columns into one uint32 hash per row."""
     h = jnp.full(cols[0].shape, np.uint32(seed_mix(seed)), jnp.uint32)
@@ -80,8 +94,7 @@ def key_digest(cols: Sequence[jnp.ndarray], seed: int = 0) -> Tuple[jnp.ndarray,
 
 def hash_u01(cols: Sequence[jnp.ndarray], seed: int = 0) -> jnp.ndarray:
     """Uniform [0,1) value per row (float32; ~2^-24 resolution)."""
-    h = hash_columns(cols, seed)
-    return h.astype(jnp.float32) * jnp.float32(1.0 / 4294967296.0)
+    return u01(hash_columns(cols, seed))
 
 
 def hash_threshold_mask(

@@ -27,7 +27,7 @@ import numpy as np
 
 from repro.relational.execute import execute_jit
 from repro.relational.plan import Plan, plan_pk
-from repro.relational.relation import SENTINEL_KEY, Relation
+from repro.relational.relation import SENTINEL_KEY, Relation, compact, next_pow2
 
 
 @dataclasses.dataclass
@@ -154,7 +154,7 @@ def _topk_merge_fn(attr: str, columns: Tuple[str, ...], capacity: int):
 
 
 def propagate_outlier_keys(
-    view_plan: Plan, base_env, index: OutlierIndex, key_capacity: int | None = None
+    view_plan: Plan, base_env, index: OutlierIndex
 ) -> Tuple[jnp.ndarray, ...]:
     """Def. 5 push-up: view pk values of rows derived from indexed records.
 
@@ -165,6 +165,11 @@ def propagate_outlier_keys(
     env = dict(base_env)
     env[index.base] = index.records
     touched = execute_jit(view_plan, env)
+    # the touched groups are few but sit in the view's whole group arena;
+    # compacted to a pow2 bucket of their count (one host sync), the pin
+    # key table stays small enough for kernels/outlier_member's VMEM path
+    cap = next_pow2(max(int(np.asarray(touched.valid).sum()), 64))
+    touched = compact(touched, min(cap, touched.capacity))
     keys = []
     for kcol in plan_pk(view_plan):
         v = touched.col(kcol)

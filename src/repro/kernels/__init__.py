@@ -26,9 +26,10 @@ the paper's profiles (§7: hashing + delta aggregation + estimation):
                     §Roofline memory-term lever — scores stay in VMEM
 
 Each kernel ships ``kernel.py`` (pl.pallas_call + explicit BlockSpec VMEM
-tiling), ``ops.py`` (jit'd padding/reshaping wrapper; interpret=True on
-CPU), and ``ref.py`` (pure-jnp oracle).  Tests sweep shapes/dtypes against
-the oracle.
+tiling), ``ops.py`` (jit'd padding/reshaping wrapper), and ``ref.py``
+(pure-jnp oracle).  Tests sweep shapes/dtypes against the oracle.  Which
+path an op takes is decided at its first call by ``platform.py``: Pallas
+compiled by Mosaic on a TPU, interpret mode only off it.
 
 Call ``enable()`` to route repro.core.hashing through the Pallas path.
 

@@ -39,10 +39,13 @@ def _corr_diff_kernel(t_new_ref, t_old_ref, mask_ref, out_ref):
     s1 = jnp.sum(d)
     s2 = jnp.sum(d * d)
     s0 = jnp.sum(m)
-    acc = jnp.zeros_like(out_ref)
-    acc = acc.at[0, 0].set(s1)
-    acc = acc.at[0, 1].set(s2)
-    acc = acc.at[0, 2].set(s0)
+    # slots [0, 0..2] built with iota/where (Mosaic lowers no scatter)
+    row = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 1)
+    first = row == 0
+    acc = jnp.where(first & (col == 0), s1, 0.0)
+    acc = jnp.where(first & (col == 1), s2, acc)
+    acc = jnp.where(first & (col == 2), s0, acc)
     out_ref[...] += acc
 
 

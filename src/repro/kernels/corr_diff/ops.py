@@ -2,13 +2,11 @@
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.kernels.corr_diff.kernel import BLOCK_R, LANES, corr_diff_tiles
+from repro.kernels.platform import interpret
 from repro.obs.kprof import profiled
-
-INTERPRET = jax.default_backend() != "tpu"
 
 
 def corr_moments(t_new: jnp.ndarray, t_old: jnp.ndarray, mask: jnp.ndarray):
@@ -27,6 +25,6 @@ def corr_moments(t_new: jnp.ndarray, t_old: jnp.ndarray, mask: jnp.ndarray):
         pad2d(t_new, jnp.float32),
         pad2d(t_old, jnp.float32),
         pad2d(mask.astype(jnp.int8), jnp.int8),
-        rows=n, padded=padded, interpret=INTERPRET,
+        rows=n, padded=padded, interpret=interpret(),
     )
     return acc[0, 0], acc[0, 1], acc[0, 2]

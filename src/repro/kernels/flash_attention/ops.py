@@ -2,13 +2,11 @@
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.flash_attention.kernel import BLOCK_K, BLOCK_Q, flash_tiles
-
-INTERPRET = jax.default_backend() != "tpu"
+from repro.kernels.platform import interpret
 
 
 def flash_attention(q, k, v, causal: bool = True):
@@ -33,7 +31,7 @@ def flash_attention(q, k, v, causal: bool = True):
     o = flash_tiles(
         to_bh(q, Sp), to_bh(k, Tp), to_bh(v, Tp),
         sm_scale=1.0 / float(np.sqrt(hd)), causal=causal, t_valid=T,
-        interpret=INTERPRET,
+        interpret=interpret(),
     )
     o = o.reshape(B, H, Sp, hd)[:, :, :S]
     return jnp.moveaxis(o, 1, 2)

@@ -5,15 +5,13 @@ the public ``fleet_merge`` dispatch.
 """
 
 from .kernel import BLOCK_G, BLOCK_R, BLOCK_V, fleet_merge_tiles
-from .ops import INTERPRET, USE_PALLAS, fleet_merge
+from .ops import fleet_merge
 from .ref import delta_only_rows, fleet_merge_ref
 
 __all__ = [
     "BLOCK_G",
     "BLOCK_R",
     "BLOCK_V",
-    "INTERPRET",
-    "USE_PALLAS",
     "delta_only_rows",
     "fleet_merge",
     "fleet_merge_ref",

@@ -10,9 +10,10 @@ Backends (same convention as kernels/fleet_moments):
 
   * XLA (default off-TPU): jits the ref.py oracle plus the key sort.
   * Pallas (default on TPU, ``use_pallas=True`` elsewhere runs the
-    interpreter): kernel.py computes the O(R·G) stale-row upsert with
-    views on lanes; the O(R+G) delta-only rows and the sort are shared
-    XLA glue inside the same jitted program.
+    interpreter): kernel.py computes the stale-row upsert with views on
+    lanes, each row tile visiting only the group slabs its keys span;
+    the O(R+G) delta-only rows and the sort are shared XLA glue inside
+    the same jitted program.
 
 Padding contract on outputs: invalid rows are key SENTINEL_KEY, values
 0.0, valid False — callers may slice or re-pad without re-masking.
@@ -25,15 +26,12 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.platform import interpret, use_pallas as _use_pallas
 from repro.obs.kprof import profiled
-from repro.relational.relation import SENTINEL_KEY
+from repro.relational.relation import SENTINEL_KEY, next_pow2
 
 from .kernel import BLOCK_G, BLOCK_R, BLOCK_V, fleet_merge_tiles
 from .ref import delta_only_rows, fleet_merge_ref
-
-# Pallas runs in interpret mode everywhere except real TPU backends.
-INTERPRET = jax.default_backend() != "tpu"
-USE_PALLAS = jax.default_backend() == "tpu"
 
 
 def _pad_to(n: int, mult: int) -> int:
@@ -65,15 +63,32 @@ def _ref_sorted(stale_keys, stale_valid, stale_vals,
     return _sort_by_key(*out)
 
 
-@functools.partial(jax.jit, static_argnames=("v", "r", "g", "interpret"))
-def _pallas_sorted(skeys_t, svals_t, ivalid_t, ivals_t, dvalid_t, dvals_t,
-                   stale_keys, stale_valid,
+@functools.partial(jax.jit, static_argnames=("gp",))
+def _slab_ranges(skeys_t, gp: int):
+    """Per BLOCK_R row tile of the (Rp, Vp) key panel: the first group slab
+    its in-range keys can hit, and how many slabs their span covers (0
+    when no key is in range) — the kernel's scalar-prefetch vectors."""
+    t = skeys_t.reshape(-1, BLOCK_R * skeys_t.shape[1])
+    inr = (t >= 0) & (t < gp)
+    lo = jnp.min(jnp.where(inr, t, gp), axis=1) // BLOCK_G
+    hi = jnp.max(jnp.where(inr, t, -1), axis=1) // BLOCK_G
+    some = jnp.any(inr, axis=1)
+    slab0 = jnp.where(some, lo, 0).astype(jnp.int32)
+    nslab = jnp.where(some, hi - lo + 1, 0).astype(jnp.int32)
+    return slab0, nslab
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("v", "r", "g", "n_slabs", "interpret"))
+def _pallas_sorted(slab0, nslab, skeys_t, svals_t, ivalid_t, ivals_t,
+                   dvalid_t, dvals_t, stale_keys, stale_valid,
                    ins_valid, ins_vals, del_valid, del_vals,
-                   v: int, r: int, g: int, interpret: bool):
-    # O(R·G) upsert on the padded transposed panels.
+                   v: int, r: int, g: int, n_slabs: int, interpret: bool):
+    # upsert on the padded transposed panels: each row tile visits only
+    # the group slabs its keys span
     upd = fleet_merge_tiles(
-        skeys_t, svals_t, ivalid_t, ivals_t, dvalid_t, dvals_t,
-        interpret=interpret,
+        slab0, nslab, skeys_t, svals_t, ivalid_t, ivals_t, dvalid_t, dvals_t,
+        n_slabs=n_slabs, interpret=interpret,
     )
     upd_vals = jnp.transpose(upd, (2, 1, 0))[:v, :r]      # (V, R, A)
     upd_keys = jnp.where(stale_valid, stale_keys.astype(jnp.int32), SENTINEL_KEY)
@@ -126,8 +141,7 @@ def fleet_merge(
             jnp.zeros((V, n), bool),
         )
 
-    up = USE_PALLAS if use_pallas is None else use_pallas
-    if not up:
+    if not _use_pallas(use_pallas):
         return profiled(
             "fleet_merge", _ref_sorted,
             stale_keys, stale_valid, stale_vals,
@@ -158,10 +172,14 @@ def fleet_merge(
         jnp.pad(del_vals.astype(jnp.float32), ((0, Vp - V), (0, Gp - G), (0, 0))),
         (2, 1, 0),
     )
+    slab0, nslab = _slab_ranges(skeys_t, Gp)
+    # the slab axis is static: one host read of the widest tile span,
+    # bucketed to a power of two so steady epochs reuse the compile
+    n_slabs = min(next_pow2(max(int(jnp.max(nslab)), 1)), Gp // BLOCK_G)
     return profiled(
         "fleet_merge", _pallas_sorted,
-        skeys_t, svals_t, ivalid_t, ivals_t, dvalid_t, dvals_t,
+        slab0, nslab, skeys_t, svals_t, ivalid_t, ivals_t, dvalid_t, dvals_t,
         stale_keys, sv, ins_valid, ins_vals, del_valid, del_vals,
         rows=V * R, padded=Vp * Rp,
-        v=V, r=R, g=G, interpret=INTERPRET,
+        v=V, r=R, g=G, n_slabs=n_slabs, interpret=interpret(),
     )

@@ -25,11 +25,8 @@ from repro.kernels.fleet_moments.kernel import (
     fleet_moments_tiles,
 )
 from repro.kernels.fleet_moments.ref import N_MOMENTS, fleet_moments_ref
+from repro.kernels.platform import interpret, use_pallas as _use_pallas
 from repro.obs.kprof import profiled
-
-# CPU containers run the kernel body in interpret mode; on TPU set False.
-INTERPRET = jax.default_backend() != "tpu"
-USE_PALLAS = jax.default_backend() == "tpu"
 
 _ref_jit = jax.jit(fleet_moments_ref)
 
@@ -58,12 +55,25 @@ def fleet_moments(
             raise ValueError(f"ragged channel panel: {a.shape} != {(V, R)}")
     if V == 0:
         return jnp.zeros((0, N_MOMENTS), jnp.float32)
-    if not (use_pallas if use_pallas is not None else USE_PALLAS):
+    if not _use_pallas(use_pallas):
         return profiled("fleet_moments", _ref_jit, *args,
                         fallback=True, rows=V, padded=V)
-    Vp = _pad_to(max(V, BLOCK_V), BLOCK_V)
-    Rp = _pad_to(max(R, BLOCK_R), BLOCK_R)
-    padded = [jnp.pad(a, ((0, Vp - V), (0, Rp - R))).T for a in args]
+    # Views ride the lane axis.  A fleet narrower than one lane tile folds
+    # its rows into the spare lanes — view v's row chunk f becomes lane
+    # v·fold + f, and the chunks' moments are summed after the pass — so
+    # padding stays bounded (unfolded, V = 2 views of 2M rows would pad
+    # eight panels to 128 lanes: 8 GiB).
+    fold = 1
+    while 2 * V * fold <= BLOCK_V:
+        fold *= 2
+    Rp = _pad_to(max(R, BLOCK_R * fold), BLOCK_R * fold)
+    Vf = V * fold
+    Vp = _pad_to(max(Vf, BLOCK_V), BLOCK_V)
+    padded = [
+        jnp.pad(jnp.pad(a, ((0, 0), (0, Rp - R))).reshape(Vf, Rp // fold),
+                ((0, Vp - Vf), (0, 0))).T
+        for a in args
+    ]
     out = profiled("fleet_moments", fleet_moments_tiles, *padded,
-                   rows=V, padded=Vp, interpret=INTERPRET)
-    return out[:N_MOMENTS, :V].T
+                   rows=Vf, padded=Vp, interpret=interpret())
+    return out[:N_MOMENTS, :Vf].reshape(N_MOMENTS, V, fold).sum(axis=2).T

@@ -21,11 +21,8 @@ import jax.numpy as jnp
 
 from repro.kernels.fleet_score.kernel import BLOCK_V, FEAT_ROWS, fleet_score_tiles
 from repro.kernels.fleet_score.ref import N_FEATURES, N_SCORES, fleet_score_ref
+from repro.kernels.platform import interpret, use_pallas as _use_pallas
 from repro.obs.kprof import profiled
-
-# CPU containers run the kernel body in interpret mode; on TPU set False.
-INTERPRET = jax.default_backend() != "tpu"
-USE_PALLAS = jax.default_backend() == "tpu"
 
 _ref_jit = jax.jit(fleet_score_ref)
 
@@ -39,15 +36,14 @@ def fleet_scores(features, use_pallas: Optional[bool] = None) -> jnp.ndarray:
     feats = jnp.asarray(features, jnp.float32)
     if feats.ndim != 2 or feats.shape[1] != N_FEATURES:
         raise ValueError(f"expected (V, {N_FEATURES}) features, got {feats.shape}")
-    up = use_pallas if use_pallas is not None else USE_PALLAS
     V = feats.shape[0]
-    if not up:
+    if not _use_pallas(use_pallas):
         return profiled("fleet_score", _ref_jit, feats,
                         fallback=True, rows=V, padded=V)
     Vp = max(BLOCK_V, ((V + BLOCK_V - 1) // BLOCK_V) * BLOCK_V)
     panel = jnp.pad(feats, ((0, Vp - V), (0, FEAT_ROWS - N_FEATURES))).T
     out = profiled("fleet_score", fleet_score_tiles, panel,
-                   rows=V, padded=Vp, interpret=INTERPRET)
+                   rows=V, padded=Vp, interpret=interpret())
     return out[:N_SCORES, :V].T
 
 

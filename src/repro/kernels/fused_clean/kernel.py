@@ -13,6 +13,9 @@ segment_aggsum); this kernel does both in ONE pass over the delta tile:
   3. fold the keep-mask into the one-hot matrix and accumulate
      ``out[g, :] += onehotᵀ @ [1 | vals]`` on the MXU — column 0 of the
      output is the kept-row count, columns 1.. are the masked column sums.
+     A row tile runs one contraction per occurrence rank (a group's k-th
+     row in the tile lands in pass k), so every group sums its rows one
+     at a time in row order: bit-equal to the executor's segment sum.
 
 No filtered intermediate ever exists: the keep decision lives only in the
 one-hot tile in VMEM.  Grid and accumulation discipline follow
@@ -35,13 +38,14 @@ from jax.experimental import pallas as pl
 # The ONE splitmix32 mixer (core/hashing): importing it makes the
 # bit-identical-hash invariant behind Prop. 2 structural — this kernel
 # cannot drift from hash_threshold/the jnp oracle by copy-edit.
-from repro.core.hashing import splitmix32
+from repro.core.hashing import splitmix32, u01
 
 BLOCK_R = 256
 BLOCK_G = 128
 
 
-def _fused_clean_kernel(seed_mix, thresh, gid_ref, pin_ref, val_ref, out_ref):
+def _fused_clean_kernel(seed_mix, thresh, gid_ref, gidrow_ref, pin_ref,
+                        val_ref, out_ref):
     """``seed_mix``/``thresh`` are baked at trace time (plan-static in SVC)."""
     gi = pl.program_id(0)  # group tile
     ri = pl.program_id(1)  # row tile
@@ -53,7 +57,7 @@ def _fused_clean_kernel(seed_mix, thresh, gid_ref, pin_ref, val_ref, out_ref):
     gid = gid_ref[...]  # (BLOCK_R, 1) int32
     # η_{a,m}: the shared mixer + compare of kernels/hash_threshold
     h = splitmix32(jnp.uint32(seed_mix) ^ splitmix32(gid.astype(jnp.uint32)))
-    u = h.astype(jnp.float32) * jnp.float32(1.0 / 4294967296.0)
+    u = u01(h)
     keep = (u < jnp.float32(thresh)) | (pin_ref[...] != 0)
     keep = keep & (gid >= 0)
 
@@ -62,11 +66,32 @@ def _fused_clean_kernel(seed_mix, thresh, gid_ref, pin_ref, val_ref, out_ref):
     cols = jax.lax.broadcasted_iota(jnp.int32, (gid.shape[0], BLOCK_G), 1)
     # the η decision folds into the one-hot: kept rows scatter, dropped
     # rows vanish — this is the "no materialized filtered intermediate"
-    onehot = ((cols == local) & keep).astype(jnp.float32)  # (BLOCK_R, BLOCK_G)
-    out_ref[...] += jax.lax.dot_general(
-        onehot, val_ref[...], (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    hit = (cols == local) & keep  # (BLOCK_R, BLOCK_G)
+    # rank of each row among the earlier rows of its key in this tile:
+    # pass k adds every group's k-th row, so a group's rows accumulate
+    # one at a time in row order — the float order of the plan
+    # executor's segment sum, which a multi-row MXU contraction would
+    # reassociate
+    n = gid.shape[0]
+    earlier = ((gidrow_ref[...] == gid)
+               & (jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+                  < jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)))
+    rank = jnp.sum(earlier.astype(jnp.int32), axis=1, keepdims=True)
+    in_tile = keep & (local >= 0) & (local < BLOCK_G)
+    passes = jnp.max(jnp.where(in_tile, rank + 1, 0))
+    vals = val_ref[...]
+
+    def one_pass(k, carry):
+        onehot = (hit & (rank == k)).astype(jnp.float32)
+        # one row per group per pass: HIGHEST makes 1·v exact on the MXU
+        out_ref[...] += jax.lax.dot_general(
+            onehot, vals, (((0,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
+        )
+        return carry
+
+    jax.lax.fori_loop(0, passes, one_pass, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("seed_mix", "thresh", "num_groups", "interpret"))
@@ -93,9 +118,10 @@ def fused_clean_tiles(
         grid=grid,
         in_specs=[
             pl.BlockSpec((br, 1), lambda g, r: (r, 0)),
+            pl.BlockSpec((1, br), lambda g, r: (0, r)),
             pl.BlockSpec((br, 1), lambda g, r: (r, 0)),
             pl.BlockSpec((br, C1), lambda g, r: (r, 0)),
         ],
         out_specs=pl.BlockSpec((BLOCK_G, C1), lambda g, r: (g, 0)),
         interpret=interpret,
-    )(gid, pin, vals)
+    )(gid, gid.reshape(1, R), pin, vals)

@@ -15,17 +15,14 @@ import jax.numpy as jnp
 
 from repro.core.hashing import seed_mix as _seed_mix
 from repro.kernels.fused_clean.kernel import BLOCK_G, BLOCK_R, fused_clean_tiles
+from repro.kernels.platform import interpret, use_pallas as _use_pallas
 from repro.obs.kprof import profiled
-
-# CPU containers run the kernel body in interpret mode; on TPU set False.
-INTERPRET = jax.default_backend() != "tpu"
 
 # Pallas interpret mode walks the grid step by step and is slower than XLA
 # on CPU, so off-TPU the fused op compiles the reference math instead — the
 # same single pass (hash → mask → segmented accumulation, no sort, no
 # materialized filtered relation), just lowered by XLA.  Tests force the
 # Pallas path with ``use_pallas=True`` to check the kernel itself.
-USE_PALLAS = jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("m", "seed", "num_groups"))
@@ -37,11 +34,11 @@ def _fused_ref_path(gid, vals, valid, pin_mask, m, seed, num_groups):
 
 @functools.partial(jax.jit, static_argnames=("num_groups",))
 def _fleet_path(gid, vals, valid, thresh, seed_mixes, num_groups):
-    from repro.core.hashing import splitmix32
+    from repro.core.hashing import splitmix32, u01
 
     V = gid.shape[0]
     h = splitmix32(seed_mixes[:, None] ^ splitmix32(gid.astype(jnp.uint32)))
-    u = h.astype(jnp.float32) * jnp.float32(1.0 / 4294967296.0)
+    u = u01(h)
     keep = (u < thresh[:, None]) & valid
     g = jnp.where(keep, gid, num_groups)  # per-view overflow slot
     nseg = num_groups + 1
@@ -104,7 +101,7 @@ def fused_clean_groupby(
     weight 1 regardless of hash).  Returns (counts (G,), sums (G, C)).
     """
     squeeze = vals.ndim == 1
-    if not (use_pallas if use_pallas is not None else USE_PALLAS):
+    if not _use_pallas(use_pallas):
         if squeeze:
             vals = vals[:, None]
         counts, sums = profiled(
@@ -135,7 +132,7 @@ def fused_clean_groupby(
     out = profiled(
         "fused_clean", fused_clean_tiles,
         gid_p, pin_p, vals_p, seed_mix=_seed_mix(seed), thresh=float(m),
-        num_groups=Gp, rows=R, padded=Rp, interpret=INTERPRET,
+        num_groups=Gp, rows=R, padded=Rp, interpret=interpret(),
     )
     out = out[:num_groups]
     counts, sums = out[:, 0], out[:, 1:]

@@ -20,7 +20,7 @@ from jax.experimental import pallas as pl
 
 # the ONE splitmix32 mixer (core/hashing): Prop. 2's bit-identical-hash
 # invariant is structural, not a copied constant block
-from repro.core.hashing import splitmix32
+from repro.core.hashing import splitmix32, u01
 
 LANES = 128
 BLOCK_R = 64  # (64, 128) uint32 tile = 32 KiB in VMEM per column
@@ -37,7 +37,7 @@ def _hash_threshold_kernel(seed_mix: int, thresh: float, *refs):
     for r in col_refs:
         c = r[...].astype(jnp.uint32)
         h = splitmix32(h ^ splitmix32(c))
-    u = h.astype(jnp.float32) * jnp.float32(1.0 / 4294967296.0)
+    u = u01(h)
     out_ref[...] = (u < jnp.float32(thresh)).astype(jnp.int8)
 
 

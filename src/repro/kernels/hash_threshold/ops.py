@@ -4,16 +4,12 @@ from __future__ import annotations
 
 from typing import Sequence
 
-import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.core.hashing import seed_mix as _seed_mix
 from repro.kernels.hash_threshold.kernel import BLOCK_R, LANES, hash_threshold_tiles
+from repro.kernels.platform import interpret
 from repro.obs.kprof import profiled
-
-# CPU containers run the kernel body in interpret mode; on TPU set False.
-INTERPRET = jax.default_backend() != "tpu"
 
 
 def hash_threshold(cols: Sequence[jnp.ndarray], m: float, seed: int = 0) -> jnp.ndarray:
@@ -32,6 +28,6 @@ def hash_threshold(cols: Sequence[jnp.ndarray], m: float, seed: int = 0) -> jnp.
     out = profiled(
         "hash_threshold", hash_threshold_tiles,
         cols2d, _seed_mix(seed), float(m), n_cols=len(cols2d),
-        rows=n, padded=padded, interpret=INTERPRET,
+        rows=n, padded=padded, interpret=interpret(),
     )
     return out.reshape(padded)[:n].astype(bool)

@@ -14,16 +14,19 @@ simultaneously, every moment the estimators need:
   2. apply the encoded interval bounds (ge/gt/le/lt per term; ±inf for
      unused sides) and the sum/count/avg op codes to form t and the row
      mask per query;
-  3. accumulate out[moment, q] += Σ_rows over the grid's row tiles:
+  3. reduce each row tile to its moments, out[tile, moment, q] = Σ_rows:
      counts, Σt, Σt², Σ(1−π)t² per side plus Σd, Σd² and the pin-aware
      Σ min(1−π_new, 1−π_old)·d² (HT_D, §6.3) for d = t_new−t_old.
 
-Grid/accumulation discipline follows fused_clean: 1-D row-tile grid, the
-(16, Q) output block revisited every step (sequential TPU grid ⇒ safe).
+Each grid step writes its own (16, Q) partial block; ops.py sums the
+partials with one XLA reduction.  Accumulating 8k row tiles into one f32
+block in grid order would cost ~8k·2⁻²⁴ ≈ 5e-4 relative error at 2M rows
+— wider than a CORR interval whose diff is small, where the exact side
+of the estimate must be exact to float rounding.
 
 Shapes: x (R, Cp) f32 panels; valid/w/ompi (R, 1) f32 row vectors;
-sel ((1+P)·Cp, Qp) f32; meta (Mp, Qp) f32; out (16, Qp) f32 with the
-moment-row layout of ref.py (rows 12..15 zero padding).
+sel ((1+P)·Cp, Qp) f32; meta (Mp, Qp) f32; out (R/BLOCK_R, 16, Qp) f32
+with the moment-row layout of ref.py (rows 12..15 zero padding).
 """
 
 from __future__ import annotations
@@ -42,8 +45,13 @@ N_OUT_ROWS = 16  # 12 moments padded to the f32 sublane multiple
 
 
 def _dot(a, b):
+    # HIGHEST: the one-hot column select must return the column's f32
+    # values exactly (the MXU's default bf16 pass would round predicate
+    # operands and move rows across range bounds)
     return jax.lax.dot_general(
-        a, b, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        a, b, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     )
 
 
@@ -81,12 +89,6 @@ def _side_rows(t, rowmask, ompi):
 def _multi_agg_kernel_two(C, P, xn_ref, vn_ref, wn_ref, on_ref,
                           xo_ref, vo_ref, wo_ref, oo_ref,
                           sel_ref, meta_ref, out_ref):
-    ri = pl.program_id(0)
-
-    @pl.when(ri == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
     sel = sel_ref[...]
     meta = meta_ref[...]
     vn, vo = vn_ref[...], vo_ref[...]
@@ -104,31 +106,26 @@ def _multi_agg_kernel_two(C, P, xn_ref, vn_ref, wn_ref, on_ref,
     od = jnp.minimum(on_ref[...], oo_ref[...])
     htd = jnp.sum(od * d * d, axis=0)
     z = jnp.zeros_like(kn)
-    out_ref[...] += jnp.stack(
+    out_ref[0] = jnp.stack(
         [kn, sn, ssn, htn, ko, so, sso, hto, kd, sd, ssd, htd, z, z, z, z]
     )
 
 
 def _multi_agg_kernel_one(C, P, xn_ref, vn_ref, wn_ref, on_ref,
                           sel_ref, meta_ref, out_ref):
-    ri = pl.program_id(0)
-
-    @pl.when(ri == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
     tn, mn = _tile_trans(xn_ref[...], vn_ref[...], wn_ref[...],
                          sel_ref[...], meta_ref[...], C, P)
     kn, sn, ssn, htn = _side_rows(tn, mn, on_ref[...])
     z = jnp.zeros_like(kn)
-    out_ref[...] += jnp.stack([kn, sn, ssn, htn] + [z] * 12)
+    out_ref[0] = jnp.stack([kn, sn, ssn, htn] + [z] * 12)
 
 
 @functools.partial(jax.jit, static_argnames=("C", "P", "interpret"))
 def multi_agg_tiles_two(xn, vn, wn, on, xo, vo, wo, oo, sel, meta,
                         C: int, P: int, interpret: bool = True) -> jnp.ndarray:
     """Two-sided scan (clean ∥ stale ∥ diff).  R % BLOCK_R == 0, C = Cp,
-    Q = Qp multiples of 128; meta rows a multiple of 8.  Out (16, Qp)."""
+    Q = Qp multiples of 128; meta rows a multiple of 8.  Out
+    (R/BLOCK_R, 16, Qp) per-tile partials."""
     R = xn.shape[0]
     Qp = sel.shape[1]
     Mp = meta.shape[0]
@@ -136,7 +133,8 @@ def multi_agg_tiles_two(xn, vn, wn, on, xo, vo, wo, oo, sel, meta,
     full = lambda r: (0, 0)
     return pl.pallas_call(
         functools.partial(_multi_agg_kernel_two, C, P),
-        out_shape=jax.ShapeDtypeStruct((N_OUT_ROWS, Qp), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((R // BLOCK_R, N_OUT_ROWS, Qp),
+                                       jnp.float32),
         grid=(R // BLOCK_R,),
         in_specs=[
             pl.BlockSpec((BLOCK_R, C), row),
@@ -150,7 +148,7 @@ def multi_agg_tiles_two(xn, vn, wn, on, xo, vo, wo, oo, sel, meta,
             pl.BlockSpec(((1 + P) * C, Qp), full),
             pl.BlockSpec((Mp, Qp), full),
         ],
-        out_specs=pl.BlockSpec((N_OUT_ROWS, Qp), full),
+        out_specs=pl.BlockSpec((1, N_OUT_ROWS, Qp), lambda r: (r, 0, 0)),
         interpret=interpret,
     )(xn, vn, wn, on, xo, vo, wo, oo, sel, meta)
 
@@ -166,7 +164,8 @@ def multi_agg_tiles_one(xn, vn, wn, on, sel, meta,
     full = lambda r: (0, 0)
     return pl.pallas_call(
         functools.partial(_multi_agg_kernel_one, C, P),
-        out_shape=jax.ShapeDtypeStruct((N_OUT_ROWS, Qp), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((R // BLOCK_R, N_OUT_ROWS, Qp),
+                                       jnp.float32),
         grid=(R // BLOCK_R,),
         in_specs=[
             pl.BlockSpec((BLOCK_R, C), row),
@@ -176,6 +175,6 @@ def multi_agg_tiles_one(xn, vn, wn, on, sel, meta,
             pl.BlockSpec(((1 + P) * C, Qp), full),
             pl.BlockSpec((Mp, Qp), full),
         ],
-        out_specs=pl.BlockSpec((N_OUT_ROWS, Qp), full),
+        out_specs=pl.BlockSpec((1, N_OUT_ROWS, Qp), lambda r: (r, 0, 0)),
         interpret=interpret,
     )(xn, vn, wn, on, sel, meta)

@@ -16,17 +16,14 @@ import jax.numpy as jnp
 
 from repro.kernels.multi_agg.kernel import BLOCK_R, LANE, multi_agg_tiles_one, multi_agg_tiles_two
 from repro.kernels.multi_agg.ref import N_MOMENTS, multi_agg_ref
+from repro.kernels.platform import interpret, use_pallas as _use_pallas
 from repro.obs.kprof import profiled
-
-# CPU containers run the kernel body in interpret mode; on TPU set False.
-INTERPRET = jax.default_backend() != "tpu"
 
 # Pallas interpret mode walks the grid step by step and is slower than XLA
 # on CPU, so off-TPU the op compiles the reference math instead — the same
 # single logical pass (one-hot column select → mask → moment accumulation),
 # just lowered by XLA.  Tests force the Pallas path with ``use_pallas=True``
 # to check the kernel itself.
-USE_PALLAS = jax.default_backend() == "tpu"
 
 _ref_two = jax.jit(multi_agg_ref)
 _ref_one = jax.jit(
@@ -70,7 +67,7 @@ def multi_agg_moments(
     Row layout of the result is ref.py's K/S/SS/HT_{NEW,OLD} + K/S/SS_D.
     """
     two = x_old is not None
-    if not (use_pallas if use_pallas is not None else USE_PALLAS):
+    if not _use_pallas(use_pallas):
         nrows = x_new.shape[0]
         if two:
             return profiled(
@@ -108,12 +105,12 @@ def multi_agg_moments(
         out = profiled(
             "multi_agg", multi_agg_tiles_two,
             xn, vn, wn, on, xo, vo, wo, oo, sel_p, meta_p,
-            rows=R, padded=Rp, C=Cp, P=P, interpret=INTERPRET,
+            rows=R, padded=Rp, C=Cp, P=P, interpret=interpret(),
         )
     else:
         out = profiled(
             "multi_agg", multi_agg_tiles_one,
             xn, vn, wn, on, sel_p, meta_p,
-            rows=R, padded=Rp, C=Cp, P=P, interpret=INTERPRET,
+            rows=R, padded=Rp, C=Cp, P=P, interpret=interpret(),
         )
-    return out[:N_MOMENTS, :Q]
+    return jnp.sum(out, axis=0)[:N_MOMENTS, :Q]

@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.hashing import splitmix32
+from repro.core.hashing import splitmix32, u01
 from repro.relational.relation import SENTINEL_KEY
 
 BLOCK_R = 256
@@ -56,7 +56,7 @@ def _outlier_member_kernel(C, seed_eta, seed_hi, seed_lo, thresh,
         h_eta = splitmix32(h_eta ^ mc)
         h_hi = splitmix32(h_hi ^ mc)
         h_lo = splitmix32(h_lo ^ mc)
-    u = h_eta.astype(jnp.float32) * jnp.float32(1.0 / 4294967296.0)
+    u = u01(h_eta)
     eta = u < jnp.float32(thresh)
 
     khi = keys_ref[0:1, :]  # (1, Kp)

@@ -34,12 +34,10 @@ from repro.kernels.outlier_member.kernel import (
     LANE,
     outlier_member_tiles,
 )
+from repro.kernels.platform import interpret as _interpret
+from repro.kernels.platform import use_pallas as _use_pallas
 from repro.obs.kprof import profiled
 from repro.relational.relation import SENTINEL_KEY, next_pow2
-
-# CPU containers run the kernel body in interpret mode; on TPU set False.
-INTERPRET = jax.default_backend() != "tpu"
-USE_PALLAS = jax.default_backend() == "tpu"
 
 # Largest key table the kernel keeps resident in VMEM ((BLOCK_R, Kp) f32
 # match tile ≈ 2 MiB at the cap); larger indices take the XLA binary-search
@@ -90,8 +88,7 @@ def _fused_xla(cols, key_cols, m: float, seed: int, with_eta: bool):
     return keep, member
 
 
-def _fused_pallas(cols, key_cols, m: float, seed: int,
-                  interpret: Optional[bool] = None):
+def _fused_pallas(cols, key_cols, m: float, seed: int):
     R = cols[0].shape[0]
     C = len(cols)
     Rp = max(BLOCK_R, ((R + BLOCK_R - 1) // BLOCK_R) * BLOCK_R)
@@ -117,7 +114,7 @@ def _fused_pallas(cols, key_cols, m: float, seed: int,
         seed_lo=seed_mix(DIGEST_SEED_LO),
         thresh=float(m),
         rows=R, padded=Rp,
-        interpret=INTERPRET if interpret is None else interpret,
+        interpret=_interpret(),
     )[:R, 0]
     return (code & 1) > 0, (code & 2) > 0
 
@@ -139,8 +136,7 @@ def fused_hash_member(
     """
     cols = tuple(jnp.asarray(c) for c in cols)
     key_cols = tuple(jnp.asarray(c) for c in key_cols)
-    up = use_pallas if use_pallas is not None else USE_PALLAS
-    if up and key_cols[0].shape[0] <= MAX_KERNEL_KEYS:
+    if _use_pallas(use_pallas) and key_cols[0].shape[0] <= MAX_KERNEL_KEYS:
         return _fused_pallas(cols, key_cols, m, seed)
     R = cols[0].shape[0]
     return profiled("outlier_member", _fused_xla,
@@ -156,8 +152,7 @@ def outlier_member(
     """Membership-only probe: probe tuple ∈ key tuples (digest identity)."""
     probe_cols = tuple(jnp.asarray(c) for c in probe_cols)
     key_cols = tuple(jnp.asarray(c) for c in key_cols)
-    up = use_pallas if use_pallas is not None else USE_PALLAS
-    if up and key_cols[0].shape[0] <= MAX_KERNEL_KEYS:
+    if _use_pallas(use_pallas) and key_cols[0].shape[0] <= MAX_KERNEL_KEYS:
         return _fused_pallas(probe_cols, key_cols, 0.0, 0)[1]
     R = probe_cols[0].shape[0]
     return profiled("outlier_member", _fused_xla,

@@ -40,7 +40,9 @@ def _segsum_kernel(gid_ref, val_ref, out_ref):
     cols = jax.lax.broadcasted_iota(jnp.int32, (gid.shape[0], BLOCK_G), 1)
     onehot = (cols == local).astype(jnp.float32)  # (BLOCK_R, BLOCK_G)
     out_ref[...] += jax.lax.dot_general(
-        onehot, vals, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        onehot, vals, (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     )
 
 

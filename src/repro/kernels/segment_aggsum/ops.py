@@ -2,13 +2,11 @@
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.kernels.segment_aggsum.kernel import BLOCK_G, BLOCK_R, segment_sum_tiles
+from repro.kernels.platform import interpret
 from repro.obs.kprof import profiled
-
-INTERPRET = jax.default_backend() != "tpu"
 
 
 def segment_sum(gid: jnp.ndarray, vals: jnp.ndarray, num_groups: int) -> jnp.ndarray:
@@ -27,7 +25,7 @@ def segment_sum(gid: jnp.ndarray, vals: jnp.ndarray, num_groups: int) -> jnp.nda
     vals_p = jnp.pad(jnp.asarray(vals, jnp.float32), ((0, Rp - R), (0, 0)))
     out = profiled(
         "segment_aggsum", segment_sum_tiles, gid_p, vals_p,
-        rows=R, padded=Rp, num_groups=Gp, interpret=INTERPRET,
+        rows=R, padded=Rp, num_groups=Gp, interpret=interpret(),
     )
     out = out[:num_groups]
     return out[:, 0] if squeeze else out

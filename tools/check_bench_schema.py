@@ -72,6 +72,7 @@ SCHEMAS = {
     },
     "BENCH_distributed.json": {
         "quick": bool,
+        "platform": str,
         "devices": int,
         "n_views": int,
         "rows_per_view": int,
