@@ -1,0 +1,6 @@
+"""Device: share of the traced window in which no operation ran on the
+chip (1 − busy union / window)."""
+
+
+def read(ctx):
+    return None if ctx.trace is None else ctx.trace["idle_pct"]
