@@ -185,7 +185,7 @@ def full_maintenance(
     env = delta_env(view_name, stale_view, deltas)
     if extra_env:
         env.update(extra_env)
-    out = execute_jit(strategy, env)
+    out = execute_jit(strategy, env, name=view_name)
     return compact(out, out_capacity or stale_view.capacity)
 
 
@@ -801,7 +801,7 @@ def clean_sample(
         plan, env = fuse_delta_groupbys(plan, env, precomputed=precomputed)
     if compact_leaves and pin_name is None:
         plan, env = _compact_eta_leaves(plan, env, m)
-    out = execute_jit(plan, env)
+    out = execute_jit(plan, env, name=view_name)
     return compact(out, out_capacity or stale_sample.capacity)
 
 

@@ -10,6 +10,7 @@ tracer records both paths as spans with explicit parent ids:
         with trace.span("drain", base=b):
             ...
         sp.set(total_s=total)          # attrs can land after the fact
+        sp.wait(out)                   # traced only: close at device completion
     trace.event("shed", base=b, seqs=[...])  # zero-duration, parented
 
 Disabled (the default) the module-level ``span()``/``event()`` are a None
@@ -56,6 +57,13 @@ class Span:
         self.attrs.update(attrs)
         return self
 
+    def wait(self, *arrays) -> None:
+        """Block until ``arrays`` are computed, so that a span called last
+        in its block closes at device completion, not at enqueue."""
+        import jax
+
+        jax.block_until_ready(arrays)
+
     def __enter__(self) -> "Span":
         return self
 
@@ -73,6 +81,9 @@ class _NoopSpan:
 
     def set(self, **attrs) -> "_NoopSpan":
         return self
+
+    def wait(self, *arrays) -> None:
+        """Disabled: no device sync."""
 
     def __enter__(self) -> "_NoopSpan":
         return self

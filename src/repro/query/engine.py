@@ -49,6 +49,7 @@ from repro.kernels.multi_agg import (
     SS_OLD,
     multi_agg_moments,
 )
+from repro.obs import trace as obs_trace
 from repro.query.batch import QueryBatch
 from repro.relational import ops
 from repro.relational.relation import Relation, Schema
@@ -160,6 +161,14 @@ def sample_panel(rel: Relation, columns: Sequence[str], m: float):
 # Moment passes
 # ---------------------------------------------------------------------------
 
+def _fetch(x) -> np.ndarray:
+    """Device-to-host read under a ``fetch`` span: it waits for the device
+    work that produces ``x``, so the span separates device waits from the
+    host work around them."""
+    with obs_trace.span("fetch", bytes=int(x.nbytes)):
+        return np.asarray(x)
+
+
 def panel_moments(cache: CorrespondenceCache, batch: QueryBatch,
                   fused: bool = True, use_pallas: Optional[bool] = None) -> np.ndarray:
     """(12, Q) host moments for a batch over the cached panel."""
@@ -170,7 +179,7 @@ def panel_moments(cache: CorrespondenceCache, batch: QueryBatch,
             cache.x_old, cache.valid_old, cache.w_old, cache.ompi_old,
             use_pallas=use_pallas,
         )
-        return np.asarray(mom)[:, :len(batch)]
+        return _fetch(mom)[:, :len(batch)]
     return _moments_per_query(cache, batch)
 
 
@@ -188,7 +197,7 @@ def _moments_per_query(cache: CorrespondenceCache, batch: QueryBatch) -> np.ndar
             cache.x_old, cache.valid_old, cache.w_old, cache.ompi_old,
             use_pallas=False,
         )
-        out[:, qi] = np.asarray(mom)[:, 0]
+        out[:, qi] = _fetch(mom)[:, 0]
     return out
 
 
@@ -199,7 +208,7 @@ def exact_batch(view: Relation, batch: QueryBatch,
         [jnp.asarray(view.col(c), jnp.float32) for c in batch.columns], axis=1
     )
     ones = jnp.ones(view.valid.shape, jnp.float32)
-    mom = np.asarray(
+    mom = _fetch(
         multi_agg_moments(x, view.valid, ones, jnp.zeros_like(ones),
                           batch.sel, batch.meta, use_pallas=use_pallas)
     )[:, :len(batch)]
@@ -241,14 +250,14 @@ def _trans_single_side(x, valid, w, batch: QueryBatch, qi: int):
 def _avg_var_new(cache_or_panel, batch: QueryBatch, qi: int) -> float:
     x, valid, w = cache_or_panel
     t, mask = _trans_single_side(x, valid, w, batch, qi)
-    return float(_masked_moments(t, mask)[3])
+    return float(_fetch(_masked_moments(t, mask)[3]))
 
 
 def _avg_var_diff(cache: CorrespondenceCache, batch: QueryBatch, qi: int) -> float:
     tn, _ = _trans_single_side(cache.x_new, cache.valid_new, cache.w_new, batch, qi)
     to, _ = _trans_single_side(cache.x_old, cache.valid_old, cache.w_old, batch, qi)
     maskd = cache.valid_new | cache.valid_old
-    return float(_masked_moments(tn - to, maskd)[3])
+    return float(_fetch(_masked_moments(tn - to, maskd)[3]))
 
 
 def run_batch(
@@ -266,7 +275,8 @@ def run_batch(
     query by the §5.2.2 HT-variance break-even.  ``materialized`` is only
     scanned (one batched pass) when at least one query resolves to CORR.
     """
-    mom = panel_moments(cache, batch, fused=fused, use_pallas=use_pallas)
+    with obs_trace.span("moments"):
+        mom = panel_moments(cache, batch, fused=fused, use_pallas=use_pallas)
     kn, sn, ssn, htn = mom[K_NEW], mom[S_NEW], mom[SS_NEW], mom[HT_NEW]
     ko, so = mom[K_OLD], mom[S_OLD]
     kd, sd, ssd = mom[K_D], mom[S_D], mom[SS_D]
@@ -284,45 +294,51 @@ def run_batch(
     if use_corr.any():
         if materialized is None:
             raise ValueError("CORR queries need the materialized view for q(S)")
-        stale = exact_batch(materialized, batch, use_pallas=use_pallas)
+        with obs_trace.span("exact_scan"):
+            stale = exact_batch(materialized, batch, use_pallas=use_pallas)
     g = _gamma(confidence)
     out: List[Estimate] = []
-    for i in range(len(batch)):
-        if batch.is_avg[i]:
-            mean_n = sn[i] / max(kn[i], 1.0)
-            if use_corr[i]:
-                mean_o = so[i] / max(ko[i], 1.0)
-                # paired mean-difference variance over the diff table,
-                # scaled by the clean-side predicate count (estimators.py)
-                var_d = _var(ssd[i], sd[i], kd[i])
-                if _ill_conditioned(ssd[i], sd[i], kd[i]):
-                    var_d = _avg_var_diff(cache, batch, i)
-                stderr = math.sqrt(var_d / max(kn[i], 1.0))
-                value = float(stale[i]) + (mean_n - mean_o)
-                method = "SVC+CORR"
+    with obs_trace.span("assemble") as sp:
+        refits = 0
+        for i in range(len(batch)):
+            if batch.is_avg[i]:
+                mean_n = sn[i] / max(kn[i], 1.0)
+                if use_corr[i]:
+                    mean_o = so[i] / max(ko[i], 1.0)
+                    # paired mean-difference variance over the diff table,
+                    # scaled by the clean-side predicate count (estimators.py)
+                    var_d = _var(ssd[i], sd[i], kd[i])
+                    if _ill_conditioned(ssd[i], sd[i], kd[i]):
+                        var_d = _avg_var_diff(cache, batch, i)
+                        refits += 1
+                    stderr = math.sqrt(var_d / max(kn[i], 1.0))
+                    value = float(stale[i]) + (mean_n - mean_o)
+                    method = "SVC+CORR"
+                else:
+                    var_n = _var(ssn[i], sn[i], kn[i])
+                    if _ill_conditioned(ssn[i], sn[i], kn[i]):
+                        var_n = _avg_var_new(
+                            (cache.x_new, cache.valid_new, cache.w_new), batch, i
+                        )
+                        refits += 1
+                    stderr = math.sqrt(var_n / max(kn[i], 1.0))
+                    value = mean_n
+                    method = "SVC+AQP"
             else:
-                var_n = _var(ssn[i], sn[i], kn[i])
-                if _ill_conditioned(ssn[i], sn[i], kn[i]):
-                    var_n = _avg_var_new(
-                        (cache.x_new, cache.valid_new, cache.w_new), batch, i
-                    )
-                stderr = math.sqrt(var_n / max(kn[i], 1.0))
-                value = mean_n
-                method = "SVC+AQP"
-        else:
-            if use_corr[i]:
-                value = float(stale[i]) + sd[i]
-                stderr = math.sqrt(max(ht_corr[i], 0.0))
-                method = "SVC+CORR"
-            else:
-                value = sn[i]
-                stderr = math.sqrt(max(htn[i], 0.0))
-                method = "SVC+AQP"
-        value = float(value)
-        out.append(
-            Estimate(value, float(stderr), value - g * stderr, value + g * stderr,
-                     method, confidence)
-        )
+                if use_corr[i]:
+                    value = float(stale[i]) + sd[i]
+                    stderr = math.sqrt(max(ht_corr[i], 0.0))
+                    method = "SVC+CORR"
+                else:
+                    value = sn[i]
+                    stderr = math.sqrt(max(htn[i], 0.0))
+                    method = "SVC+AQP"
+            value = float(value)
+            out.append(
+                Estimate(value, float(stderr), value - g * stderr,
+                         value + g * stderr, method, confidence)
+            )
+        sp.set(refits=refits)
     return out
 
 
@@ -337,39 +353,44 @@ def run_batch_aqp(
     """AQP-only batch: one one-sided scan of the clean sample, no
     correspondence join, no stale-view access — the cheapest batch path,
     used by ``ViewManager.query_batch(prefer="aqp")``."""
-    x, valid, w, ompi = sample_panel(clean_sample, batch.columns, m)
-    if fused:
-        mom = np.asarray(
-            multi_agg_moments(x, valid, w, ompi, batch.sel, batch.meta,
-                              use_pallas=use_pallas)
-        )[:, :len(batch)]
-    else:
-        mom = np.zeros((12, len(batch)), np.float32)
-        for qi in range(len(batch)):
-            one = multi_agg_moments(
-                x, valid, w, ompi,
-                batch.sel[:, qi:qi + 1], batch.meta[:, qi:qi + 1],
-                use_pallas=use_pallas,
-            )
-            mom[:, qi] = np.asarray(one)[:, 0]
+    with obs_trace.span("moments"):
+        x, valid, w, ompi = sample_panel(clean_sample, batch.columns, m)
+        if fused:
+            mom = _fetch(
+                multi_agg_moments(x, valid, w, ompi, batch.sel, batch.meta,
+                                  use_pallas=use_pallas)
+            )[:, :len(batch)]
+        else:
+            mom = np.zeros((12, len(batch)), np.float32)
+            for qi in range(len(batch)):
+                one = multi_agg_moments(
+                    x, valid, w, ompi,
+                    batch.sel[:, qi:qi + 1], batch.meta[:, qi:qi + 1],
+                    use_pallas=use_pallas,
+                )
+                mom[:, qi] = _fetch(one)[:, 0]
     kn, sn, ssn, htn = mom[K_NEW], mom[S_NEW], mom[SS_NEW], mom[HT_NEW]
     g = _gamma(confidence)
     out: List[Estimate] = []
-    for i in range(len(batch)):
-        if batch.is_avg[i]:
-            var_n = _var(ssn[i], sn[i], kn[i])
-            if _ill_conditioned(ssn[i], sn[i], kn[i]):
-                var_n = _avg_var_new((x, valid, w), batch, i)
-            value = sn[i] / max(kn[i], 1.0)
-            stderr = math.sqrt(var_n / max(kn[i], 1.0))
-        else:
-            value = sn[i]
-            stderr = math.sqrt(max(htn[i], 0.0))
-        value = float(value)
-        out.append(
-            Estimate(value, float(stderr), value - g * stderr, value + g * stderr,
-                     "SVC+AQP", confidence)
-        )
+    with obs_trace.span("assemble") as sp:
+        refits = 0
+        for i in range(len(batch)):
+            if batch.is_avg[i]:
+                var_n = _var(ssn[i], sn[i], kn[i])
+                if _ill_conditioned(ssn[i], sn[i], kn[i]):
+                    var_n = _avg_var_new((x, valid, w), batch, i)
+                    refits += 1
+                value = sn[i] / max(kn[i], 1.0)
+                stderr = math.sqrt(var_n / max(kn[i], 1.0))
+            else:
+                value = sn[i]
+                stderr = math.sqrt(max(htn[i], 0.0))
+            value = float(value)
+            out.append(
+                Estimate(value, float(stderr), value - g * stderr,
+                         value + g * stderr, "SVC+AQP", confidence)
+            )
+        sp.set(refits=refits)
     return out
 
 

@@ -3,7 +3,7 @@
 from __future__ import annotations
 
 import functools
-from typing import Mapping
+from typing import Mapping, Optional
 
 import jax
 
@@ -69,14 +69,22 @@ def execute(p: Plan, env: Mapping[str, Relation]) -> Relation:
 
 
 @functools.lru_cache(maxsize=256)
-def _jitted_executor(plan: Plan):
-    return jax.jit(lambda env: execute(plan, env))
+def _jitted_executor(plan: Plan, name: Optional[str] = None):
+    def run(env):
+        return execute(plan, env)
+
+    # the device program is named ``jit_plan_<name>`` (the view, else the
+    # root node's type), so a profile can tell one plan from another
+    run.__name__ = run.__qualname__ = f"plan_{name or type(plan).__name__}"
+    return jax.jit(run)
 
 
-def execute_jit(plan: Plan, env: Mapping[str, Relation]) -> Relation:
+def execute_jit(plan: Plan, env: Mapping[str, Relation],
+                name: Optional[str] = None) -> Relation:
     """Compiled plan execution (plans are frozen/hashable; cached per plan).
 
-    Retraces when relation capacities change; steady-state maintenance hits
-    the cache.
+    ``name`` labels the compiled program (``jit_plan_<name>``).  Retraces
+    when relation capacities change; steady-state maintenance hits the
+    cache.
     """
-    return _jitted_executor(plan)(dict(env))
+    return _jitted_executor(plan, name)(dict(env))

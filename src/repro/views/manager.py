@@ -296,7 +296,6 @@ class ViewManager:
             self.pending_segments.append(seg)
             self._merged_cache.clear()
             self.ingested_rows[base] = self.ingested_rows.get(base, 0) + n_rows
-            obs_trace.event("ingest", base=base, rows=n_rows)
         for mv in self.views.values():
             if base in mv.delta_bases:
                 mv.stale_since_ivm = True
@@ -313,17 +312,23 @@ class ViewManager:
         key = (lo, hi)
         merged = self._merged_cache.get(key)
         if merged is None:
-            ins: Dict[str, List[Relation]] = {}
-            dels: Dict[str, List[Relation]] = {}
-            for seg in self.pending_segments[lo:]:
-                for b, r in seg.inserts.items():
-                    ins.setdefault(b, []).append(r)
-                for b, r in seg.deletes.items():
-                    dels.setdefault(b, []).append(r)
-            merged = DeltaSet(
-                inserts={b: _concat_many(rs) for b, rs in ins.items()},
-                deletes={b: _concat_many(rs) for b, rs in dels.items()},
-            )
+            with obs_trace.span("concat", segments=hi - lo,
+                                **self.obs_attrs) as sp:
+                ins: Dict[str, List[Relation]] = {}
+                dels: Dict[str, List[Relation]] = {}
+                for seg in self.pending_segments[lo:]:
+                    for b, r in seg.inserts.items():
+                        ins.setdefault(b, []).append(r)
+                    for b, r in seg.deletes.items():
+                        dels.setdefault(b, []).append(r)
+                merged = DeltaSet()
+                rows = cap = uploaded = 0
+                for src, side in ((ins, merged.inserts), (dels, merged.deletes)):
+                    for b, rs in src.items():
+                        side[b], n, up = _concat_many(rs)
+                        rows, uploaded = rows + n, uploaded + up
+                        cap = max(cap, side[b].capacity)
+                sp.set(rows=rows, cap=cap, bytes=uploaded)
             self._merged_cache[key] = merged
         return merged
 
@@ -458,7 +463,8 @@ class ViewManager:
         mv.clean_sample = flag_outliers(mv.clean_sample, mv.outlier_pin)
         mv.stale_sample = flag_outliers(mv.stale_sample, mv.outlier_pin)
         mv.corr_cache = None  # samples moved: new correspondence window
-        jnp.asarray(mv.clean_sample.valid).block_until_ready()
+        with obs_trace.span("sync", view=view_name, **self.obs_attrs):
+            jnp.asarray(mv.clean_sample.valid).block_until_ready()
         dt = self.clock() - t0 + float(_extra_s) + lat_s
         mv.maintenance_s = dt
         mv.refresh_s = dt
@@ -619,8 +625,10 @@ class ViewManager:
                 try:
                     self._inject_fault("kernel", None)
                     merged, precomputed = fleet_clean_merge(jobs)
-                    for rel in merged.values():
-                        jnp.asarray(rel.valid).block_until_ready()
+                    for name, rel in merged.items():
+                        with obs_trace.span("sync", view=name,
+                                            **self.obs_attrs):
+                            jnp.asarray(rel.valid).block_until_ready()
                 except Exception:
                     if not isolate:
                         raise
@@ -729,7 +737,8 @@ class ViewManager:
                 self._deltas_for(mv), extra_env=self.base,
                 out_capacity=mv.materialized.capacity,
             )
-            jnp.asarray(scratch.valid).block_until_ready()
+            with obs_trace.span("sync", view=view_name, **self.obs_attrs):
+                jnp.asarray(scratch.valid).block_until_ready()
             return self.clock() - t0
         snap = _view_snapshot(mv)
         with obs_trace.span("maintain", view=view_name,
@@ -759,7 +768,8 @@ class ViewManager:
             extra_env=self.base,
             out_capacity=mv.materialized.capacity,
         )
-        jnp.asarray(mv.materialized.valid).block_until_ready()
+        with obs_trace.span("sync", view=view_name, **self.obs_attrs):
+            jnp.asarray(mv.materialized.valid).block_until_ready()
         dt = self.clock() - t0 + lat_s
         mv.stale_sample = compact(
             hashing.apply_hash(mv.materialized, mv.view.pk, mv.m, mv.seed, pin=mv.outlier_pin),
@@ -877,15 +887,19 @@ class ViewManager:
                             sample_version=mv.sample_version,
                             **self.obs_attrs):
             results: List[Optional[Estimate]] = [None] * len(queries)
-            cols = sample_columns(mv.clean_sample)
-            batched = [i for i, q in enumerate(queries) if is_encodable(q, cols)]
+            with obs_trace.span("encode"):
+                cols = sample_columns(mv.clean_sample)
+                batched = [i for i, q in enumerate(queries)
+                           if is_encodable(q, cols)]
+                if batched:
+                    batch = QueryBatch.encode([queries[i] for i in batched],
+                                              cols)
             fast = set(batched)
             for i, q in enumerate(queries):
                 if i not in fast:
                     results[i] = self._query_fallback(mv, q, confidence,
                                                       prefer, rng)
             if batched:
-                batch = QueryBatch.encode([queries[i] for i in batched], cols)
                 if prefer == "aqp":
                     # AQP never needs the stale side: skip the correspondence
                     # join entirely and scan only the clean sample
@@ -906,9 +920,15 @@ class ViewManager:
 
     def _corr_cache(self, mv: ManagedView):
         if mv.corr_cache is None:
-            mv.corr_cache = build_correspondence_cache(
-                mv.clean_sample, mv.stale_sample, mv.m
-            )
+            with obs_trace.span("corr_build", view=mv.view.name,
+                                **self.obs_attrs) as sp:
+                cache = build_correspondence_cache(
+                    mv.clean_sample, mv.stale_sample, mv.m
+                )
+                sp.set(rows=int(cache.x_new.shape[0]))
+                # traced only; the batch's first fetch waits on these anyway
+                sp.wait(cache.x_new, cache.x_old)
+            mv.corr_cache = cache
         return mv.corr_cache
 
     def _query_fallback(
@@ -983,8 +1003,9 @@ def _restore_view(mv: ManagedView, snap: dict) -> None:
         setattr(mv, k, v)
 
 
-def _concat_many(rels: List[Relation]) -> Relation:
-    """Concatenate delta segments into one size-bucketed arena.
+def _concat_many(rels: List[Relation]) -> Tuple[Relation, int, int]:
+    """Concatenate delta segments into one size-bucketed arena; returns the
+    arena, its valid row count and the bytes uploaded to build it.
 
     Capacity is sized by the VALID row count (next pow2, ≥4096), so a
     steady ingest stream keeps one stable shape → the compiled cleaning
@@ -1010,7 +1031,7 @@ def _concat_many(rels: List[Relation]) -> Relation:
     n_valid = int(sum(m.sum() for m in masks))
     cap = _next_pow2(max(n_valid, 4096))
     if len(rels) == 1 and rels[0].valid.shape[0] == cap:
-        return rels[0]
+        return rels[0], n_valid, 0
     bodies = {
         c: np.concatenate([np.asarray(r.col(c))[m] for r, m in zip(rels, masks)])
         for c in schema.columns
@@ -1028,7 +1049,8 @@ def _concat_many(rels: List[Relation]) -> Relation:
         cols[c] = jnp.asarray(arena)
     valid = np.zeros((cap,), dtype=bool)
     valid[:n_valid] = True
-    return Relation(cols, jnp.asarray(valid), schema)
+    uploaded = valid.nbytes + sum(c.nbytes for c in cols.values())
+    return Relation(cols, jnp.asarray(valid), schema), n_valid, uploaded
 
 
 def _next_pow2(n: int) -> int:

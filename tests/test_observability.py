@@ -453,6 +453,124 @@ def test_service_trace_exports_and_reconciles(tmp_path):
     assert drains and all(r["parent"] in epochs for r in drains)
 
 
+def _estimate_and_epoch_workload():
+    """Two epochs of inserts on a two-view fleet, each followed by two
+    query batches per view straight through ``query_batch`` (no result
+    cache), the second view's forced to CORR so the exact scan runs."""
+    vm, rng = _fleet()
+    svc = StreamingViewService(vm, StreamConfig(auto_refresh=False))
+    vm.stream = svc
+    qs = [Query(agg="sum", col="total"), Query(agg="avg", col="total")]
+    for epoch in range(2):
+        for i in range(2):
+            svc.offer(f"Log{i}", inserts=_delta(1000 * (i + 1) + 30 * epoch,
+                                                30, 8, rng), seq=epoch)
+        svc.refresh()
+        for _ in range(2):
+            vm.query_batch("v0", qs)
+            vm.query_batch("v1", qs, prefer="corr")
+    return svc
+
+
+def _spans(records, name):
+    return [r for r in records if r["kind"] == "span" and r["name"] == name]
+
+
+def test_query_batch_spans_split_estimate():
+    tr = obs_trace.enable()
+    _estimate_and_epoch_workload()
+    records = list(tr.records)
+    by_id = {r["id"]: r for r in records}
+    estimates = [r for r in _spans(records, "estimate")
+                 if r["attrs"]["view"] == "v1"]
+    assert len(estimates) == 4
+    for k, est in enumerate(estimates):
+        kids = [r for r in records if r.get("parent") == est["id"]]
+        names = [r["name"] for r in kids]
+        # the first batch after each clean rebuilds the correspondence
+        want = ["encode", "moments", "exact_scan", "assemble"]
+        if k % 2 == 0:
+            want.insert(1, "corr_build")
+        assert names == want
+        for kid in kids:
+            assert est["t0"] <= kid["t0"] and kid["t1"] <= est["t1"]
+    builds = [r for r in _spans(records, "corr_build")
+              if r["attrs"]["view"] == "v1"]
+    assert all(r["attrs"]["rows"] > 0 for r in builds)
+    fetches = _spans(records, "fetch")
+    assert fetches and all(r["attrs"]["bytes"] > 0 for r in fetches)
+    parents = {by_id[r["parent"]]["name"] for r in fetches}
+    assert parents == {"moments", "exact_scan"}
+    assert all(r["attrs"]["refits"] >= 0 for r in _spans(records, "assemble"))
+
+
+def test_epoch_spans_concat_and_sync():
+    tr = obs_trace.enable()
+    _estimate_and_epoch_workload()
+    records = list(tr.records)
+    concats = _spans(records, "concat")
+    assert len(concats) == 2  # one pending-delta merge per epoch
+    for epoch, r in enumerate(concats, 1):
+        a = r["attrs"]  # one 30-row segment per base and epoch
+        assert a["segments"] == 2 * epoch
+        assert a["rows"] == 60 * epoch and a["cap"] == 4096
+        assert a["bytes"] > 0
+    syncs = _spans(records, "sync")
+    assert {r["attrs"]["view"] for r in syncs} == {"v0", "v1"}
+
+
+def test_tracer_off_adds_no_span_and_no_sync(monkeypatch):
+    """The new spans cost nothing when tracing is off: nothing is recorded,
+    and the only device waits are the ones each ``sync`` span wraps."""
+    import jax
+    from jax._src.array import ArrayImpl
+
+    calls = {"method": 0, "function": 0, "spans": 0}
+    method, function = ArrayImpl.block_until_ready, jax.block_until_ready
+    opened = Tracer.span
+
+    def counted_method(self):
+        calls["method"] += 1
+        return method(self)
+
+    def counted_function(x):
+        calls["function"] += 1
+        return function(x)
+
+    def counted_span(self, name, **attrs):
+        calls["spans"] += 1
+        return opened(self, name, **attrs)
+
+    monkeypatch.setattr(ArrayImpl, "block_until_ready", counted_method)
+    monkeypatch.setattr(jax, "block_until_ready", counted_function)
+    monkeypatch.setattr(Tracer, "span", counted_span)
+    _estimate_and_epoch_workload()
+    off = dict(calls)
+    assert off["spans"] == 0 and off["function"] == 0
+
+    tr = obs_trace.enable()
+    _estimate_and_epoch_workload()
+    records = list(tr.records)
+    # untraced, the waits are exactly the existing ones the sync spans wrap
+    assert off["method"] == len(_spans(records, "sync")) > 0
+    # traced, each correspondence build adds one wait for its panels
+    assert calls["function"] == len(_spans(records, "corr_build")) > 0
+
+
+def test_estimate_and_epoch_spans_reconcile(tmp_path):
+    obs_trace.enable()
+    svc = _estimate_and_epoch_workload()
+    path = tmp_path / "trace.jsonl"
+    export_service_trace(svc, str(path))
+    meta, records = load_jsonl(str(path))
+    names = {r["name"] for r in records if r["kind"] == "span"}
+    assert names >= {"encode", "corr_build", "moments", "exact_scan",
+                     "assemble", "fetch", "concat", "sync"}
+    assert not any(r["name"] == "ingest" for r in records)
+    result = reconcile(meta, records)
+    assert result["ok"], result["problems"]
+
+
 def test_observatory_panel_reconciles_live():
     obs_trace.enable()
     kprof.set_profiler(kprof.KernelProfiler())

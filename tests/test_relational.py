@@ -136,3 +136,22 @@ def test_compact_preserves_rows():
     c = compact(sel, 15)
     assert oracle.rows_equal(oracle.from_relation(c), oracle.from_relation(sel),
                              keys=("fid",))
+
+
+@pytest.mark.parametrize("name,program", [("joinView", "jit_plan_joinView"),
+                                          (None, "jit_plan_GroupByNode")])
+def test_compiled_plan_is_named_after_its_view(name, program):
+    """A profile tells compiled plans apart by name: ``plan_<view>``, else
+    ``plan_<root node>``."""
+    from repro.relational.execute import _jitted_executor, execute_jit
+    from repro.relational.plan import GroupByNode, Scan
+
+    rel = from_columns({"k": np.arange(8, dtype=np.int32),
+                        "g": np.arange(8, dtype=np.int32) % 2,
+                        "v": np.ones(8, np.float32)}, pk=["k"])
+    plan = GroupByNode(child=Scan("T", pk=("k",)), keys=("g",),
+                       aggs=(("total", "sum", "v"),), num_groups=4)
+    text = _jitted_executor(plan, name).lower({"T": rel}).as_text()
+    assert f"module @{program}" in text
+    out = to_host(execute_jit(plan, {"T": rel}, name=name))
+    assert sorted(out["total"]) == [4.0, 4.0]
