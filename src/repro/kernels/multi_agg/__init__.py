@@ -4,7 +4,9 @@ One pass over the correspondence-aligned sample panel accumulates, for all
 Q queries of an encoded batch simultaneously, the masked weighted sums,
 counts, sums of squares, and Horvitz-Thompson variance terms that
 ``svc_aqp`` / ``svc_corr`` / ``variance_comparison`` need — a single scan
-instead of ~4Q scans.  See repro.query for the engine that feeds it.
+instead of ~4Q scans.  On a TPU each pass is one jitted program over a
+lane-dense slab (rows on lanes, queries on sublanes; kernel.py).  See
+repro.query for the engine that feeds it.
 """
 
 from repro.kernels.multi_agg.ops import multi_agg_moments

@@ -1,9 +1,13 @@
-"""jit wrapper: pad panels/tables to tile multiples and dispatch.
+"""jit wrapper: one jitted program per batched moment pass.
 
 ``multi_agg_moments`` is the op the batched query engine (repro.query)
-calls for its fused single-scan moment pass.  Shapes are padded to stable
-tile multiples, so a steady dashboard workload hits the jit cache instead
-of retracing per query batch.
+calls for its fused single-scan moment pass.  On the Pallas path each
+call is ONE compiled program (``multi_agg_tiles_two`` / ``_one``): it
+packs each side's (R, C) panel and its three row vectors into the kernel's
+lane-dense (S, Rp) slab, turns the one-hot selectors and bounds into the
+(Qp, 128) query table, runs the kernel and sums its per-tile partials.
+Shapes come from the inputs alone, so a steady dashboard workload hits
+the jit cache instead of retracing per query batch.
 """
 
 from __future__ import annotations
@@ -14,7 +18,14 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.multi_agg.kernel import BLOCK_R, LANE, multi_agg_tiles_one, multi_agg_tiles_two
+from repro.kernels.multi_agg.kernel import (
+    LANE,
+    SUBLANE,
+    lane_block,
+    multi_agg_scan,
+    pad_to,
+    slab_rows,
+)
 from repro.kernels.multi_agg.ref import N_MOMENTS, multi_agg_ref
 from repro.kernels.platform import interpret, use_pallas as _use_pallas
 from repro.obs.kprof import profiled
@@ -31,17 +42,62 @@ _ref_one = jax.jit(
 )
 
 
-def _pad_to(n: int, mult: int) -> int:
-    return ((n + mult - 1) // mult) * mult
-
-
-def _pad_side(x, valid, w, ompi, Rp, Cp):
+def _slab(x, valid, w, ompi, S, Rp):
+    """(S, Rp) f32 slab, rows on lanes: x's columns, valid, w, ompi, zeros."""
     R, C = x.shape
-    x = jnp.pad(jnp.asarray(x, jnp.float32), ((0, Rp - R), (0, Cp - C)))
-    v = jnp.pad(jnp.asarray(valid, jnp.float32), (0, Rp - R))[:, None]
-    w = jnp.pad(jnp.asarray(w, jnp.float32), (0, Rp - R))[:, None]
-    o = jnp.pad(jnp.asarray(ompi, jnp.float32), (0, Rp - R))[:, None]
-    return x, v, w, o
+    rows = jnp.concatenate([
+        jnp.asarray(x, jnp.float32).T,
+        jnp.asarray(valid, jnp.float32)[None],
+        jnp.asarray(w, jnp.float32)[None],
+        jnp.asarray(ompi, jnp.float32)[None],
+    ])
+    return jnp.pad(rows, ((0, S - rows.shape[0]), (0, Rp - R)))
+
+
+def _query_table(sel, meta, C, Qp):
+    """(Qp, 128) f32: per query the column index of each selector block
+    (−1 where the block selects nothing), then its meta rows."""
+    Q = sel.shape[1]
+    blocks = jnp.asarray(sel, jnp.float32).reshape(-1, C, Q)
+    idx = jnp.where(jnp.any(blocks != 0, axis=1),
+                    jnp.argmax(blocks, axis=1), -1).astype(jnp.float32)
+    tab = jnp.concatenate([idx, jnp.asarray(meta, jnp.float32)]).T
+    return jnp.pad(tab, ((0, Qp - Q), (0, LANE - tab.shape[1])))
+
+
+def _geometry(R, C, Q, sides):
+    """(S, Qp, lane tile, Rp) of a pass over an (R, C) panel."""
+    S = slab_rows(C)
+    Qp = pad_to(Q, SUBLANE)
+    block = lane_block(S, Qp, R, sides)
+    return S, Qp, block, pad_to(R, block)
+
+
+def _pass(sides, sel, meta, interpret):
+    R, C = sides[0][0].shape
+    Q = sel.shape[1]
+    P = sel.shape[0] // C - 1
+    if 3 + 5 * P > LANE:
+        raise ValueError(f"{P} predicate terms do not fit one query-table row")
+    S, Qp, block, Rp = _geometry(R, C, Q, len(sides))
+    out = multi_agg_scan([_slab(*side, S, Rp) for side in sides],
+                         _query_table(sel, meta, C, Qp), C, P, block, interpret)
+    return jnp.sum(out, axis=0)[:Q, :N_MOMENTS].T
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def multi_agg_tiles_two(xn, vn, wn, on, xo, vo, wo, oo, sel, meta,
+                        interpret: bool = True) -> jnp.ndarray:
+    """Two-sided pass (clean ∥ stale ∥ diff) → (12, Q) f32."""
+    return _pass([(xn, vn, wn, on), (xo, vo, wo, oo)], sel, meta, interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def multi_agg_tiles_one(xn, vn, wn, on, sel, meta,
+                        interpret: bool = True) -> jnp.ndarray:
+    """One-sided pass (e.g. exact batch over the full view) → (12, Q) f32;
+    the OLD and D rows are zero."""
+    return _pass([(xn, vn, wn, on)], sel, meta, interpret)
 
 
 def multi_agg_moments(
@@ -67,8 +123,8 @@ def multi_agg_moments(
     Row layout of the result is ref.py's K/S/SS/HT_{NEW,OLD} + K/S/SS_D.
     """
     two = x_old is not None
+    nrows, C = x_new.shape
     if not _use_pallas(use_pallas):
-        nrows = x_new.shape[0]
         if two:
             return profiled(
                 "multi_agg", _ref_two,
@@ -86,31 +142,16 @@ def multi_agg_moments(
             sel, meta,
             fallback=True, rows=nrows, padded=nrows,
         )
-
-    R, C = x_new.shape
-    Q = sel.shape[1]
-    P = sel.shape[0] // C - 1
-    Rp = _pad_to(max(R, BLOCK_R), BLOCK_R)
-    Cp = _pad_to(C, LANE)
-    Qp = _pad_to(Q, LANE)
-    Mp = _pad_to(meta.shape[0], 8)
-
-    sel3 = jnp.asarray(sel, jnp.float32).reshape(1 + P, C, Q)
-    sel_p = jnp.pad(sel3, ((0, 0), (0, Cp - C), (0, Qp - Q))).reshape((1 + P) * Cp, Qp)
-    meta_p = jnp.pad(jnp.asarray(meta, jnp.float32), ((0, Mp - meta.shape[0]), (0, Qp - Q)))
-
-    xn, vn, wn, on = _pad_side(x_new, valid_new, w_new, ompi_new, Rp, Cp)
+    padded = _geometry(nrows, C, sel.shape[1], 2 if two else 1)[3]
     if two:
-        xo, vo, wo, oo = _pad_side(x_old, valid_old, w_old, ompi_old, Rp, Cp)
-        out = profiled(
+        return profiled(
             "multi_agg", multi_agg_tiles_two,
-            xn, vn, wn, on, xo, vo, wo, oo, sel_p, meta_p,
-            rows=R, padded=Rp, C=Cp, P=P, interpret=interpret(),
+            x_new, valid_new, w_new, ompi_new,
+            x_old, valid_old, w_old, ompi_old, sel, meta,
+            rows=nrows, padded=padded, interpret=interpret(),
         )
-    else:
-        out = profiled(
-            "multi_agg", multi_agg_tiles_one,
-            xn, vn, wn, on, sel_p, meta_p,
-            rows=R, padded=Rp, C=Cp, P=P, interpret=interpret(),
-        )
-    return jnp.sum(out, axis=0)[:N_MOMENTS, :Q]
+    return profiled(
+        "multi_agg", multi_agg_tiles_one,
+        x_new, valid_new, w_new, ompi_new, sel, meta,
+        rows=nrows, padded=padded, interpret=interpret(),
+    )

@@ -498,29 +498,79 @@ def _random_batch(rng, C, Q, P):
     return _jnp.asarray(sel), _jnp.asarray(meta)
 
 
-@pytest.mark.parametrize("shape", [(64, 2, 3, 1), (300, 5, 9, 2), (1024, 3, 17, 1)])
-def test_multi_agg_two_sided_kernel_matches_ref(shape):
-    R, C, Q, P = shape
-    rng = np.random.default_rng(R + Q)
+# (sides, R, C, Q, P, all-invalid side): C = 1, 4, 6, 13 give slabs of
+# S = 8, 8, 16, 16 sublanes; R from below one lane tile to several tiles
+# with a ragged last one (9000 rows at Q = 8 and 2500 at Q = 130)
+_MULTI_AGG_CASES = [
+    (2, 64, 2, 3, 1, None),
+    (2, 300, 5, 9, 2, None),
+    (2, 1024, 3, 17, 1, None),
+    (1, 100, 4, 5, 1, None),
+    (1, 513, 2, 12, 2, None),
+    (2, 64, 1, 1, 0, None),
+    (2, 9000, 4, 8, 1, None),
+    (2, 700, 6, 9, 2, "old"),
+    (2, 2500, 13, 130, 2, None),
+    (1, 300, 1, 8, 0, None),
+    (1, 9000, 13, 9, 1, None),
+    (1, 200, 6, 130, 1, "new"),
+]
+
+
+@pytest.mark.parametrize(
+    "sides,R,C,Q,P,invalid", _MULTI_AGG_CASES,
+    ids=[f"s{c[0]}-R{c[1]}-C{c[2]}-Q{c[3]}-P{c[4]}" + (f"-{c[5]}0" if c[5] else "")
+         for c in _MULTI_AGG_CASES],
+)
+def test_multi_agg_kernel_matches_ref(sides, R, C, Q, P, invalid):
+    rng = np.random.default_rng(R * 7 + Q * 3 + C + sides)
     xn, vn, wn, on = _random_panel(rng, R, C)
     xo, vo, wo, oo = _random_panel(rng, R, C)
+    if invalid == "new":
+        vn = _jnp.zeros_like(vn)
+    if invalid == "old":
+        vo = _jnp.zeros_like(vo)
     sel, meta = _random_batch(rng, C, Q, P)
-    want = np.asarray(multi_agg_ref(xn, vn, wn, on, sel, meta, xo, vo, wo, oo))
-    got = np.asarray(
-        multi_agg_moments(xn, vn, wn, on, sel, meta, xo, vo, wo, oo, use_pallas=True)
-    )
+    old = (xo, vo, wo, oo) if sides == 2 else ()
+    want = np.asarray(multi_agg_ref(xn, vn, wn, on, sel, meta, *old))
+    got = np.asarray(multi_agg_moments(xn, vn, wn, on, sel, meta, *old,
+                                       use_pallas=True))
+    assert got.shape == (12, Q)
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=1e-2)
 
 
-@pytest.mark.parametrize("shape", [(100, 4, 5, 1), (513, 2, 12, 2)])
-def test_multi_agg_one_sided_kernel_matches_ref(shape):
-    R, C, Q, P = shape
-    rng = np.random.default_rng(R * 3 + Q)
-    xn, vn, wn, on = _random_panel(rng, R, C)
-    sel, meta = _random_batch(rng, C, Q, P)
-    want = np.asarray(multi_agg_ref(xn, vn, wn, on, sel, meta))
-    got = np.asarray(multi_agg_moments(xn, vn, wn, on, sel, meta, use_pallas=True))
-    np.testing.assert_allclose(got, want, rtol=2e-5, atol=1e-2)
+@pytest.mark.parametrize("sides", [1, 2])
+def test_multi_agg_bounds_at_non_bf16_values_are_exact(sides):
+    """A range bound equal to a column value that bfloat16 cannot hold keeps
+    or drops exactly the rows the reference does: the column select must
+    hand the predicate its f32 value unrounded."""
+    from repro.kernels.multi_agg import K_NEW, K_OLD, S_NEW
+
+    R, C, Q = 1000, 4, 8
+    rng = np.random.default_rng(11 + sides)
+    edge = np.float32(1.0 + 2.0 ** -20)  # rounds to 1.0 in bf16
+    x = rng.choice(np.array([1.0, edge, 1.0 + 2.0 ** -19], np.float32),
+                   (R, C)).astype(np.float32)
+    valid = _jnp.asarray(rng.uniform(size=R) < 0.9)
+    w = _jnp.ones(R, _jnp.float32)
+    ompi = _jnp.zeros(R, _jnp.float32)
+    sel = np.zeros((2 * C, Q), np.float32)
+    meta = np.zeros((6, Q), np.float32)
+    meta[2:4], meta[4:6] = -np.inf, np.inf
+    for q in range(Q):
+        meta[1, q] = q % 2  # avg (its count is the kept rows), else sum
+        sel[q % C, q] = 1.0
+        sel[C + (q // 2) % C, q] = 1.0
+        meta[2 + q % 4, q] = edge  # ge, gt, le, lt = the edge value
+    args = (_jnp.asarray(x), valid, w, ompi, _jnp.asarray(sel), _jnp.asarray(meta))
+    old = args[:4] if sides == 2 else ()
+    want = np.asarray(multi_agg_ref(*args, *old))
+    got = np.asarray(multi_agg_moments(*args, *old, use_pallas=True))
+    rows = [K_NEW, K_OLD] if sides == 2 else [K_NEW]
+    np.testing.assert_array_equal(got[rows], want[rows])
+    np.testing.assert_allclose(got[S_NEW], want[S_NEW], rtol=1e-6)
+    # the edge rows are what separates the bounds: kept by ge/le, dropped by gt/lt
+    assert len(set(want[K_NEW, 1::2].tolist())) > 1
 
 
 def test_multi_agg_ht_d_excludes_pinned_rows():

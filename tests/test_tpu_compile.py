@@ -65,8 +65,9 @@ def spec(topo):
 
 
 def _compiles_to_kernel(fn, *args):
-    text = jax.jit(fn).lower(*args).compile().as_text()
-    assert "tpu_custom_call" in text
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
 
 
 def test_fused_clean_compiles(spec):
@@ -92,20 +93,20 @@ def test_outlier_member_compiles(spec):
 
 @pytest.mark.parametrize("sides", [1, 2])
 def test_multi_agg_compiles(spec, sides):
-    from repro.kernels.multi_agg.kernel import (
-        LANE,
-        multi_agg_tiles_one,
-        multi_agg_tiles_two,
-    )
+    """The whole moment pass — slab pack, kernel, partial sums — as the
+    engine calls it: the joined panel two-sided, the view one-sided, with
+    4 columns, 8 queries and 1 predicate term.  Its temporaries stay under
+    256 MB: no copy of the panel or of a row vector padded to 128 lanes."""
+    from repro.kernels.multi_agg.ops import multi_agg_tiles_one, multi_agg_tiles_two
 
-    P = 1
-    side = [spec((ROWS_JOINED, LANE))] + [spec((ROWS_JOINED, 1))] * 3
-    tail = [spec(((1 + P) * LANE, LANE)), spec((8, LANE))]
+    C, Q, P = 4, 8, 1
+    rows = ROWS_JOINED if sides == 2 else ROWS_VIEW
+    side = [spec((rows, C)), spec((rows,), jnp.bool_), spec((rows,)), spec((rows,))]
+    tail = [spec(((1 + P) * C, Q)), spec((2 + 4 * P, Q))]
     fn = multi_agg_tiles_two if sides == 2 else multi_agg_tiles_one
-    _compiles_to_kernel(
-        functools.partial(fn, C=LANE, P=P, interpret=False),
-        *(side * sides + tail),
-    )
+    compiled = _compiles_to_kernel(functools.partial(fn, interpret=False),
+                                   *(side * sides + tail))
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
 
 
 def test_fleet_moments_compiles(spec):
